@@ -22,6 +22,7 @@ import sys
 import time
 
 from repro.core.engine import ENGINES
+from repro.launch.compile_cache import enable_compile_cache
 
 # (key, module, slow, entrypoint) — slow suites are multi-minute
 # end-to-end sweeps; the rest finish in seconds and form the
@@ -46,6 +47,7 @@ MODULES = [
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--only", default=None,

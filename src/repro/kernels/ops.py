@@ -1,20 +1,19 @@
 """Public jit'd wrappers over the Pallas kernels.
 
-On a real TPU backend the kernels run compiled (``interpret=False``);
-on this CPU container they run in interpret mode, and callers that want
-XLA-native CPU performance can pass ``impl='ref'`` to use the jnp
-oracles.  The default (``impl='auto'``) picks the kernel on TPU and the
-reference elsewhere — so the same call sites are production-correct on
-both.
+:func:`_on_tpu` is the one place that decides what the wrappers run.  On
+a TPU backend every wrapper runs its compiled Pallas kernel; on any other
+backend (the CPU tests) it runs the jnp oracle from ``kernels/ref.py``.
+Nothing on the chip can reach the oracle, and an error raised while the
+backend starts propagates instead of reading as "not a TPU".  Tests that
+want the kernels themselves off the chip call the kernel modules directly
+with ``interpret=True`` (``tests/test_kernels.py``).
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
 
 import jax
-import jax.numpy as jnp
 
 from repro.kernels import ref as _ref
 from repro.kernels.flash_attention import flash_attention as _flash
@@ -24,63 +23,44 @@ from repro.kernels.router_topk import router_topk as _router
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
-
-
-def _pick(impl: str) -> str:
-    if impl != "auto":
-        return impl
-    return "kernel" if _on_tpu() else "ref"
+    return jax.default_backend() == "tpu"
 
 
 # --------------------------------------------------------------------- #
-@functools.partial(jax.jit, static_argnames=("causal", "window", "scale", "impl", "interpret"))
-def flash_attention(q, k, v, causal=True, window=None, scale=None, impl="auto", interpret=False):
-    mode = _pick(impl)
-    if mode == "ref":
+@functools.partial(jax.jit, static_argnames=("causal", "window", "scale"))
+def flash_attention(q, k, v, causal=True, window=None, scale=None):
+    if not _on_tpu():
         return _ref.flash_attention_ref(q, k, v, causal=causal, window=window, scale=scale)
-    return _flash(q, k, v, causal=causal, window=window, scale=scale,
-                  interpret=interpret or not _on_tpu())
+    return _flash(q, k, v, causal=causal, window=window, scale=scale)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("scale", "window", "impl", "interpret")
-)
+@functools.partial(jax.jit, static_argnames=("scale", "window"))
 def paged_attention(q, k_pages, v_pages, block_table, lengths=None, scale=None,
-                    page_pos=None, q_pos=None, window=None,
-                    impl="auto", interpret=False):
-    mode = _pick(impl)
-    if mode == "ref":
+                    page_pos=None, q_pos=None, window=None):
+    if not _on_tpu():
         return _ref.paged_attention_ref(
             q, k_pages, v_pages, block_table, lengths, scale=scale,
             page_pos=page_pos, q_pos=q_pos, window=window)
     return _paged(q, k_pages, v_pages, block_table, lengths, scale=scale,
-                  page_pos=page_pos, q_pos=q_pos, window=window,
-                  interpret=interpret or not _on_tpu())
+                  page_pos=page_pos, q_pos=q_pos, window=window)
 
 
-@functools.partial(jax.jit, static_argnames=("impl", "interpret"))
-def page_gather(src, frames, impl="auto", interpret=False):
-    mode = _pick(impl)
-    if mode == "ref":
+@jax.jit
+def page_gather(src, frames):
+    if not _on_tpu():
         return _ref.page_gather_ref(src, frames)
-    return _gather(src, frames, interpret=interpret or not _on_tpu())
+    return _gather(src, frames)
 
 
-@functools.partial(jax.jit, static_argnames=("impl", "interpret"), donate_argnums=(0,))
-def page_scatter(dst, frames, pages, impl="auto", interpret=False):
-    mode = _pick(impl)
-    if mode == "ref":
+@functools.partial(jax.jit, donate_argnums=(0,))
+def page_scatter(dst, frames, pages):
+    if not _on_tpu():
         return _ref.page_scatter_ref(dst, frames, pages)
-    return _scatter(dst, frames, pages, interpret=interpret or not _on_tpu())
+    return _scatter(dst, frames, pages)
 
 
-@functools.partial(jax.jit, static_argnames=("k", "impl", "interpret"))
-def router_topk(logits, k, impl="auto", interpret=False):
-    mode = _pick(impl)
-    if mode == "ref":
+@functools.partial(jax.jit, static_argnames=("k",))
+def router_topk(logits, k):
+    if not _on_tpu():
         return _ref.router_topk_ref(logits, k)
-    return _router(logits, k, interpret=interpret or not _on_tpu())
+    return _router(logits, k)

@@ -3,29 +3,32 @@
   PYTHONPATH=src python -m repro.launch.serve --arch tinyllama-1.1b --smoke \
       --requests 4 --prompt-len 48 --max-new 32 --policy tpp
 
-Drives :class:`repro.serving.ServingEngine` (continuous batching, paged
-two-tier KV, TPP placement) and prints per-phase placement stats — the
-production loop the multi-pod ``serve_step`` dry-run lowers.
+Drives :class:`repro.serving.ServingEngine` on its batched data plane
+(continuous batching, paged two-tier KV, TPP placement; the decode step
+runs ``kernels.paged_attention`` and migrations run ``page_gather`` /
+``page_scatter``) and prints throughput on the device JAX reports plus
+placement stats.  ``chip_smoke.py`` calls :func:`serve` the same way.
 """
 
 from __future__ import annotations
 
 import argparse
 import time
+from typing import Any, Dict, List, Tuple
 
 import jax
 import numpy as np
 
-from repro.core import Tier, TppConfig
+from repro.core import TppConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import init_params
 from repro.serving import EngineConfig, ServingEngine
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
-    ap.add_argument("--smoke", action="store_true",
-                    help="reduced config (full configs are dry-run only on CPU)")
+    ap.add_argument("--smoke", action="store_true", help="reduced config")
     ap.add_argument("--requests", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=48)
     ap.add_argument("--max-new", type=int, default=32)
@@ -36,10 +39,20 @@ def main() -> None:
     ap.add_argument("--num-slow", type=int, default=256)
     ap.add_argument("--topk-pages", type=int, default=4)
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    return ap
 
+
+def serve(args: argparse.Namespace) -> Tuple[ServingEngine, Dict[str, Any]]:
+    """Serve ``args.requests`` random prompts to completion.
+
+    Returns the engine and its stats, extended with the outputs, the
+    tokens generated and the host-clock seconds of set-up (params, engine,
+    prefill), of the first decode step (which compiles the step) and of
+    the later steps (which compile again where a shape grows).
+    """
     from repro.configs import get_config, get_smoke_config
 
+    t0 = time.perf_counter()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     params = init_params(jax.random.PRNGKey(args.seed), cfg)
     eng = ServingEngine(
@@ -49,6 +62,7 @@ def main() -> None:
             num_slow=args.num_slow, topk_pages=args.topk_pages,
             policy=args.policy,
             tpp=TppConfig(demote_budget=64, promote_budget=32),
+            data_plane="batched",
         ),
         seed=args.seed,
     )
@@ -58,18 +72,38 @@ def main() -> None:
                         max_new=args.max_new)
         for _ in range(args.requests)
     ]
-    t0 = time.time()
-    steps = 0
+    t1 = time.perf_counter()
+    first_step_s = 0.0
     while any(not eng.requests[r].done for r in rids):
-        eng.step()
-        steps += 1
-    dt = time.time() - t0
-    s = eng.stats()
-    toks = sum(len(eng.requests[r].out) for r in rids)
-    print(f"{toks} tokens in {steps} steps ({toks/dt:.1f} tok/s on CPU)")
-    print(f"policy={args.policy} local={s['local_fraction']:.3f} "
-          f"demoted={s['demoted']} promoted={s['promoted']} "
-          f"migrated={s['migrated_bytes']/1e6:.1f}MB")
+        eng.step()  # ends in a host read of the step's tokens
+        if eng.steps == 1:
+            first_step_s = time.perf_counter() - t1
+    t2 = time.perf_counter()
+    outs: List[List[int]] = [eng.requests[r].out for r in rids]
+    stats = eng.stats()
+    stats.update(
+        outputs=outs,
+        tokens=sum(len(o) for o in outs),
+        setup_s=t1 - t0,
+        first_step_s=first_step_s,
+        later_steps_s=t2 - t1 - first_step_s,
+    )
+    dev = jax.devices()[0]
+    steady = stats["tokens"] - len(rids)  # the first step yields one per request
+    print(f"device {dev.platform} {dev.device_kind} x{len(jax.devices())}")
+    print(f"setup {stats['setup_s']:.2f}s, first step {stats['first_step_s']:.2f}s, "
+          f"{stats['tokens']} tokens in {stats['steps']} steps, later steps "
+          f"{stats['later_steps_s']:.2f}s "
+          f"({steady / max(stats['later_steps_s'], 1e-9):.1f} tok/s)")
+    print(f"policy={args.policy} local={stats['local_fraction']:.3f} "
+          f"demoted={stats['demoted']} promoted={stats['promoted']} "
+          f"migrated={stats['migrated_bytes']/1e6:.1f}MB")
+    return eng, stats
+
+
+def main() -> None:
+    enable_compile_cache()
+    eng, _ = serve(build_parser().parse_args())
     eng.kv.pool.check_invariants()
 
 

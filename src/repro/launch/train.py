@@ -34,6 +34,7 @@ import jax.numpy as jnp
 from repro import optim
 from repro.checkpoint import CheckpointManager
 from repro.data import DataConfig, make_batches
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import ModelConfig, init_params, loss_fn
 from repro.optim.adamw import AdamWConfig, cosine_schedule
 
@@ -198,6 +199,7 @@ def train_loop(
 # CLI
 # --------------------------------------------------------------------- #
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description="train an assigned arch")
     ap.add_argument("--arch", required=True)
     ap.add_argument("--steps", type=int, default=100)

@@ -168,3 +168,33 @@ def test_flash_matches_chunked_jnp_path():
     np.testing.assert_allclose(
         np.asarray(a), np.asarray(jnp.moveaxis(b, 1, 2)), atol=2e-5
     )
+
+
+# --------------------------------------------------------------------- #
+# kernels.ops: the one device decision
+def test_ops_runs_the_reference_off_tpu():
+    from repro.kernels import ops
+
+    assert jax.default_backend() != "tpu"
+    assert ops._on_tpu() is False
+    src = jnp.arange(6 * 2 * 8, dtype=jnp.float32).reshape(6, 2, 8)
+    idx = jnp.asarray([4, 1], jnp.int32)
+    assert "pallas_call" not in str(jax.make_jaxpr(ops.page_gather)(src, idx))
+    np.testing.assert_array_equal(
+        np.asarray(ops.page_gather(src, idx)),
+        np.asarray(ref.page_gather_ref(src, idx)))
+
+
+def test_ops_device_check_propagates_backend_errors(monkeypatch):
+    from repro.kernels import ops
+
+    def broken_backend():
+        raise RuntimeError("backend failed to initialise")
+
+    monkeypatch.setattr(jax, "default_backend", broken_backend)
+    with pytest.raises(RuntimeError, match="failed to initialise"):
+        ops._on_tpu()
+    # a fresh trace asks the backend too, and must not fall back
+    src = jnp.zeros((5, 3, 7), jnp.float32)
+    with pytest.raises(RuntimeError, match="failed to initialise"):
+        ops.page_gather(src, jnp.asarray([2], jnp.int32))

@@ -151,3 +151,51 @@ class TestZero1:
         out = zero1_shardings(sh, shapes, mesh, axis="data")
         assert out["a"].spec == P("data", None)
         assert out["b"].spec == P()  # small leaf untouched
+
+
+class TestCompileCache:
+    def _record_updates(self, monkeypatch):
+        calls = []
+        monkeypatch.setattr(jax.config, "update", lambda *a: calls.append(a))
+        return calls
+
+    def test_leaves_a_set_env_dir_alone(self, monkeypatch, tmp_path):
+        from repro.launch.compile_cache import enable_compile_cache
+
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        calls = self._record_updates(monkeypatch)
+        assert enable_compile_cache() == str(tmp_path)
+        assert calls == []
+
+    def test_defaults_to_a_fixed_path_in_the_checkout(self, monkeypatch):
+        from pathlib import Path
+
+        from repro.launch import compile_cache
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        calls = self._record_updates(monkeypatch)
+        root = Path(compile_cache.__file__).resolve().parents[3]
+        assert (root / "pyproject.toml").is_file()
+        want = str(root / ".jax_cache")
+        assert compile_cache.enable_compile_cache() == want
+        assert compile_cache.enable_compile_cache() == want
+        assert calls == [("jax_compilation_cache_dir", want)] * 2
+
+
+def test_serve_runs_the_batched_plane_on_the_reported_device(capsys):
+    from repro.launch.serve import build_parser, serve
+
+    args = build_parser().parse_args([
+        "--arch", "tinyllama-1.1b", "--smoke", "--page-size", "4",
+        "--num-fast", "8", "--num-slow", "64", "--requests", "3",
+        "--prompt-len", "24", "--max-new", "6",
+    ])
+    eng, stats = serve(args)
+    assert eng.ecfg.data_plane == "batched"
+    assert [len(o) for o in stats["outputs"]] == [6, 6, 6]
+    assert all(0 <= t < eng.cfg.vocab for o in stats["outputs"] for t in o)
+    assert stats["tokens"] == 18 and stats["demoted"] > 0
+    eng.kv.pool.check_invariants()
+    dev = jax.devices()[0]
+    out = capsys.readouterr().out
+    assert f"device {dev.platform} {dev.device_kind}" in out
