@@ -1,7 +1,6 @@
 """Render EXPERIMENTS.md tables from results artifacts.
 
   PYTHONPATH=src python -m benchmarks.render_tables dryrun   # §D1 table
-  PYTHONPATH=src python -m benchmarks.render_tables roofline # §RL1 table
   PYTHONPATH=src python -m benchmarks.render_tables bench results/bench_output.txt
 """
 
@@ -59,26 +58,6 @@ def dryrun_table() -> str:
     return "\n".join(lines)
 
 
-def roofline_table() -> str:
-    from benchmarks.roofline import analyze
-
-    rows = analyze(DRYRUN)
-    lines = [
-        "| arch | shape | compute [s] | memory [s] | collective [s] | bound | useful | roofline |",
-        "|---|---|---|---|---|---|---|---|",
-    ]
-    for r in sorted(rows, key=lambda x: (x["arch"], x["shape"])):
-        if r["status"] == "skipped":
-            lines.append(f"| {r['arch']} | {r['shape']} | — | — | — | skip | — | — |")
-            continue
-        lines.append(
-            f"| {r['arch']} | {r['shape']} | {r['t_compute_s']:.2e} | "
-            f"{r['t_memory_s']:.2e} | {r['t_collective_s']:.2e} | "
-            f"{r['bottleneck']} | {r['useful_ratio']:.2f} | {r['roofline_frac']:.3f} |"
-        )
-    return "\n".join(lines)
-
-
 def bench_table(path: str) -> str:
     lines = ["| benchmark | derived |", "|---|---|"]
     with open(path) as f:
@@ -96,7 +75,5 @@ if __name__ == "__main__":
     what = sys.argv[1] if len(sys.argv) > 1 else "dryrun"
     if what == "dryrun":
         print(dryrun_table())
-    elif what == "roofline":
-        print(roofline_table())
     elif what == "bench":
         print(bench_table(sys.argv[2]))

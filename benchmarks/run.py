@@ -42,7 +42,6 @@ MODULES = [
     ("serving", "benchmarks.serving_bench", True, "run"),
     ("traffic", "benchmarks.traffic_bench", True, "run"),
     ("kernels", "benchmarks.kernel_bench", False, "run"),
-    ("roofline", "benchmarks.roofline", True, "run"),
 ]
 
 

@@ -28,6 +28,14 @@ Two data planes (``EngineConfig.data_plane``, DESIGN.md §6):
   move in staged ``page_gather``/``page_scatter`` batches.  Identical
   greedy tokens and VmStat trajectories (tests/test_serving_parity.py).
 
+Phase spans: the decode step, prefill and release are marked with
+``jax.profiler.TraceAnnotation`` spans named ``serve.*`` (``serve.step``
+around its flush, score, selection, tables, dispatch, policy and interval
+phases; ``serve.prefill``; ``serve.finish``; a host read of a device
+result is a ``*.wait`` span). A profiler records them on the clock of the
+device planes; with none active each is a no-op of about a microsecond
+of host CPU time.
+
 The engine reports per-step slow-tier page hits to the policy
 (`TppPolicy` or any baseline from ``repro.core.baselines``), which
 migrates payloads through the cache's migration hook — real buffer
@@ -44,6 +52,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import PageType, Tier, TppConfig, make_policy
 from repro.kernels import ops as kernel_ops
@@ -328,16 +337,17 @@ class ServingEngine:
 
     def finish(self, rid: int) -> Request:
         """Release a sequence; returns its (now detached) Request."""
-        seq = self.seqs.pop(rid)
-        for pid in seq.pages:
-            self._summaries.pop(pid, None)
-            self.kv.free_page(pid)
-        req = self.requests.pop(rid)
-        if self.ecfg.data_plane == "batched":
-            slot = self._slot_of.pop(rid)
-            self._ksum = self._ksum.at[slot].set(0.0)
-            self._kcnt = self._kcnt.at[slot].set(0.0)
-            self._free_slots.append(slot)
+        with TraceAnnotation("serve.finish", rid=rid):
+            seq = self.seqs.pop(rid)
+            for pid in seq.pages:
+                self._summaries.pop(pid, None)
+                self.kv.free_page(pid)
+            req = self.requests.pop(rid)
+            if self.ecfg.data_plane == "batched":
+                slot = self._slot_of.pop(rid)
+                self._ksum = self._ksum.at[slot].set(0.0)
+                self._kcnt = self._kcnt.at[slot].set(0.0)
+                self._free_slots.append(slot)
         return req
 
     # ---------------------------------------------------------------- #
@@ -404,10 +414,18 @@ class ServingEngine:
         seq = self.seqs[req.rid]
         if len(req.prompt) <= 1:
             return
-        k_all, v_all = self._prefill_forward(req)  # (L, S, Hkv, D)
-        if self.ecfg.data_plane == "batched":
-            self._prefill_write_batched(seq, k_all, v_all)
-            return
+        with TraceAnnotation("serve.prefill", rid=req.rid, tokens=len(req.prompt)):
+            with TraceAnnotation("serve.prefill.forward"):
+                k_all, v_all = self._prefill_forward(req)  # (L, S, Hkv, D)
+            with TraceAnnotation("serve.prefill.write"):
+                if self.ecfg.data_plane == "batched":
+                    self._prefill_write_batched(seq, k_all, v_all)
+                else:
+                    self._prefill_write_reference(seq, k_all, v_all)
+
+    def _prefill_write_reference(self, seq: _Seq, k_all: jax.Array,
+                                 v_all: jax.Array) -> None:
+        """Land the prompt KV one token at a time (the reference plane)."""
         L, S = k_all.shape[0], k_all.shape[1]
         kv_all = jnp.concatenate(
             [k_all.reshape(L, S, -1), v_all.reshape(L, S, -1)], axis=-1
@@ -489,36 +507,39 @@ class ServingEngine:
         active = [s for s in self.seqs.values()
                   if not s.paused and not s.detached
                   and not self.requests[s.rid].done]
-        self.last_hits = {}
-        if self.ecfg.data_plane == "batched":
-            out, slow_hits, fast_hits = self._decode_batched(active)
-        else:
-            out = {}
-            slow_hits, fast_hits = [], []
-            for seq in active:
-                tok, s_hits, f_hits = self._decode_one(seq)
-                out[seq.rid] = tok
-                slow_hits += s_hits
-                fast_hits += f_hits
-                self.last_hits[seq.rid] = (len(f_hits), len(s_hits))
-        for rid, tok in out.items():
-            req = self.requests[rid]
-            req.out.append(tok)
-            if len(req.out) >= req.max_new:
-                req.done = True
-        if self.control is not None:
-            # per-tenant hotness + slowdown telemetry (tier-split feeds
-            # the slowdown controller's measured per-tenant slowdown)
-            self.control.note_hits(
-                np.fromiter(fast_hits, np.int64, count=len(fast_hits)),
-                np.fromiter(slow_hits, np.int64, count=len(slow_hits)),
-            )
-        # Uniform PlacementPolicy protocol: every policy receives both hit
-        # streams (NUMA balancing samples fast hits; the rest ignore them).
-        self.policy.step(slow_hits, fast_hits)
-        self.steps += 1
-        if self.steps % 4 == 0:
-            self.kv.pool.end_interval()  # also ticks control.note_interval
+        with TraceAnnotation("serve.step", lanes=len(active)):
+            self.last_hits = {}
+            if self.ecfg.data_plane == "batched":
+                out, slow_hits, fast_hits = self._decode_batched(active)
+            else:
+                out = {}
+                slow_hits, fast_hits = [], []
+                for seq in active:
+                    tok, s_hits, f_hits = self._decode_one(seq)
+                    out[seq.rid] = tok
+                    slow_hits += s_hits
+                    fast_hits += f_hits
+                    self.last_hits[seq.rid] = (len(f_hits), len(s_hits))
+            for rid, tok in out.items():
+                req = self.requests[rid]
+                req.out.append(tok)
+                if len(req.out) >= req.max_new:
+                    req.done = True
+            with TraceAnnotation("serve.policy"):
+                if self.control is not None:
+                    # per-tenant hotness + slowdown telemetry (tier-split feeds
+                    # the slowdown controller's measured per-tenant slowdown)
+                    self.control.note_hits(
+                        np.fromiter(fast_hits, np.int64, count=len(fast_hits)),
+                        np.fromiter(slow_hits, np.int64, count=len(slow_hits)),
+                    )
+                # Uniform PlacementPolicy protocol: every policy receives both hit
+                # streams (NUMA balancing samples fast hits; the rest ignore them).
+                self.policy.step(slow_hits, fast_hits)
+            self.steps += 1
+            if self.steps % 4 == 0:
+                with TraceAnnotation("serve.interval"):
+                    self.kv.pool.end_interval()  # also ticks control.note_interval
         return out
 
     # ------------------------- reference plane ---------------------- #
@@ -658,84 +679,93 @@ class ServingEngine:
         scores = None
         if (ecfg.topk_pages not in (None, 0)
                 and any(len(s.pages) > ecfg.recent_pages for s in active)):
-            slot_ids = jnp.asarray(
-                [self._slot_of[s.rid] for s in active], jnp.int32
-            )
-            scores = np.asarray(self._score_fn(
-                self._probe_params, self._ksum, self._kcnt,
-                jnp.asarray(toks), slot_ids,
-            ))
+            with TraceAnnotation("serve.score"):
+                slot_ids = jnp.asarray(
+                    [self._slot_of[s.rid] for s in active], jnp.int32
+                )
+                scores = self._score_fn(
+                    self._probe_params, self._ksum, self._kcnt,
+                    jnp.asarray(toks), slot_ids,
+                )
+            with TraceAnnotation("serve.score.wait"):
+                scores = np.asarray(scores)
 
         # selection + touch/tier accounting, in sequence order (the same
         # access stream the reference plane emits)
         sels: List[List[int]] = []
         s_hits: List[int] = []
         f_hits: List[int] = []
-        for b, seq in enumerate(active):
-            n_older = max(0, len(seq.pages) - ecfg.recent_pages)
-            older_scores = (scores[b, :n_older] if scores is not None
-                            else np.zeros(n_older, np.float32))
-            sel = self._select_pages(seq, older_scores)
-            sels.append(sel)
-            nf = ns = 0
-            for pid in sel:
-                tier = self.kv.pool.touch(pid)
-                if tier == Tier.SLOW:
-                    s_hits.append(pid)
-                    ns += 1
-                else:
-                    f_hits.append(pid)
-                    nf += 1
-            self.last_hits[seq.rid] = (nf, ns)
+        with TraceAnnotation("serve.select") as span:
+            for b, seq in enumerate(active):
+                n_older = max(0, len(seq.pages) - ecfg.recent_pages)
+                older_scores = (scores[b, :n_older] if scores is not None
+                                else np.zeros(n_older, np.float32))
+                sel = self._select_pages(seq, older_scores)
+                sels.append(sel)
+                nf = ns = 0
+                for pid in sel:
+                    tier = self.kv.pool.touch(pid)
+                    if tier == Tier.SLOW:
+                        s_hits.append(pid)
+                        ns += 1
+                    else:
+                        f_hits.append(pid)
+                        nf += 1
+                self.last_hits[seq.rid] = (nf, ns)
+            span.set_metadata(fast=len(f_hits), slow=len(s_hits))
 
-        # allocate every sequence's write target (page-boundary allocs
-        # land here; touch order above matches the reference plane —
-        # touches never move frames, so the interleave is immaterial)
-        writes = [self._ensure_page(seq) for seq in active]
-        self._grow_summaries(max(len(s.pages) for s in active))
+        with TraceAnnotation("serve.tables") as span:
+            # allocate every sequence's write target (page-boundary allocs
+            # land here; touch order above matches the reference plane —
+            # touches never move frames, so the interleave is immaterial)
+            writes = [self._ensure_page(seq) for seq in active]
+            self._grow_summaries(max(len(s.pages) for s in active))
 
-        # per-step block tables: selected pages (+ the write page when a
-        # boundary alloc created it after selection), padded to buckets
-        entries = []
-        for b, seq in enumerate(active):
-            ent = list(sels[b])
-            if writes[b][0] not in ent:
-                ent.append(writes[b][0])
-            entries.append(ent)
-        Bp = _bucket(B)
-        MPp = _bucket(max(len(e) for e in entries))
-        trash = self.kv.trash_frame
-        bt = np.full((Bp, MPp), trash, np.int32)
-        ps = np.full((Bp, MPp), PAD_PAGE_POS, np.int32)
-        qpos = np.zeros(Bp, np.int32)
-        wframe = np.full(Bp, trash, np.int32)
-        wslot = np.zeros(Bp, np.int32)
-        slot_arr = np.full(Bp, ecfg.max_seqs, np.int32)
-        gi_arr = np.zeros(Bp, np.int32)
-        toks_in = np.zeros(Bp, np.int32)
-        for b, seq in enumerate(active):
-            page_index = {pid: i for i, pid in enumerate(seq.pages)}
-            for j, pid in enumerate(entries[b]):
-                bt[b, j] = self.kv.global_frame(pid)
-                ps[b, j] = page_index[pid] * P
-            qpos[b] = seq.cur_len
-            wframe[b] = self.kv.global_frame(writes[b][0])
-            wslot[b] = writes[b][1]
-            slot_arr[b] = self._slot_of[seq.rid]
-            gi_arr[b] = len(seq.pages) - 1
-            toks_in[b] = toks[b]
+            # per-step block tables: selected pages (+ the write page when a
+            # boundary alloc created it after selection), padded to buckets
+            entries = []
+            for b, seq in enumerate(active):
+                ent = list(sels[b])
+                if writes[b][0] not in ent:
+                    ent.append(writes[b][0])
+                entries.append(ent)
+            Bp = _bucket(B)
+            MPp = _bucket(max(len(e) for e in entries))
+            trash = self.kv.trash_frame
+            bt = np.full((Bp, MPp), trash, np.int32)
+            ps = np.full((Bp, MPp), PAD_PAGE_POS, np.int32)
+            qpos = np.zeros(Bp, np.int32)
+            wframe = np.full(Bp, trash, np.int32)
+            wslot = np.zeros(Bp, np.int32)
+            slot_arr = np.full(Bp, ecfg.max_seqs, np.int32)
+            gi_arr = np.zeros(Bp, np.int32)
+            toks_in = np.zeros(Bp, np.int32)
+            for b, seq in enumerate(active):
+                page_index = {pid: i for i, pid in enumerate(seq.pages)}
+                for j, pid in enumerate(entries[b]):
+                    bt[b, j] = self.kv.global_frame(pid)
+                    ps[b, j] = page_index[pid] * P
+                qpos[b] = seq.cur_len
+                wframe[b] = self.kv.global_frame(writes[b][0])
+                wslot[b] = writes[b][1]
+                slot_arr[b] = self._slot_of[seq.rid]
+                gi_arr[b] = len(seq.pages) - 1
+                toks_in[b] = toks[b]
+            span.set_metadata(bp=Bp, mpp=MPp)
 
-        out_toks, self.kv.k_store, self.kv.v_store, self._ksum, self._kcnt = (
-            self._step_fn(
-                self.kv.k_store, self.kv.v_store, self._ksum, self._kcnt,
-                self.params, self.layers,
-                jnp.asarray(toks_in), jnp.asarray(qpos),
-                jnp.asarray(bt), jnp.asarray(ps),
-                jnp.asarray(wframe), jnp.asarray(wslot),
-                jnp.asarray(slot_arr), jnp.asarray(gi_arr),
+        with TraceAnnotation("serve.dispatch"):
+            out_toks, self.kv.k_store, self.kv.v_store, self._ksum, self._kcnt = (
+                self._step_fn(
+                    self.kv.k_store, self.kv.v_store, self._ksum, self._kcnt,
+                    self.params, self.layers,
+                    jnp.asarray(toks_in), jnp.asarray(qpos),
+                    jnp.asarray(bt), jnp.asarray(ps),
+                    jnp.asarray(wframe), jnp.asarray(wslot),
+                    jnp.asarray(slot_arr), jnp.asarray(gi_arr),
+                )
             )
-        )
-        out_toks = np.asarray(out_toks)
+        with TraceAnnotation("serve.tokens.wait"):
+            out_toks = np.asarray(out_toks)
         out: Dict[int, int] = {}
         for b, seq in enumerate(active):
             seq.cur_len += 1

@@ -43,6 +43,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.analysis.plan_verify import CopyOp, check_plan, plan_from_staged
 from repro.core import PagePool, PageType, Tier, TppConfig
@@ -195,37 +196,38 @@ class TieredKVCache:
             return
         pending, self._pending = self._pending, []
         self._pending_src, self._pending_dst = set(), set()
-        if self.plan_check:
-            self.last_plan = plan_from_staged(pending)
-            check_plan(
-                self.last_plan,
-                num_frames=self.trash_frame + 1,
-                trash_frame=self.trash_frame,
-                staging="gathers-first",
-            )
-        # pad every batch to one shared power-of-two width via the trash
-        # frame (a self-copy of garbage): batch-size jitter then never
-        # forces a gather/scatter recompile
-        self._flush_width = max(self._flush_width, bucket(max(
-            sum(c.demote for c in pending),
-            sum(not c.demote for c in pending),
-        )))
-        batches = []  # (dst_frames, staged_k, staged_v) — gather phase
-        for demote in (True, False):
-            group = [c for c in pending if c.demote == demote]
-            if not group:
-                continue
-            pad = [self.trash_frame] * (self._flush_width - len(group))
-            src = jnp.asarray([c.src for c in group] + pad, jnp.int32)
-            dst = jnp.asarray([c.dst for c in group] + pad, jnp.int32)
-            batches.append((
-                dst,
-                kernel_ops.page_gather(self.k_store, src),
-                kernel_ops.page_gather(self.v_store, src),
-            ))
-        for dst, staged_k, staged_v in batches:  # scatter phase
-            self.k_store = kernel_ops.page_scatter(self.k_store, dst, staged_k)
-            self.v_store = kernel_ops.page_scatter(self.v_store, dst, staged_v)
+        n_demote = sum(c.demote for c in pending)
+        with TraceAnnotation("serve.flush", demote=n_demote,
+                             promote=len(pending) - n_demote):
+            if self.plan_check:
+                self.last_plan = plan_from_staged(pending)
+                check_plan(
+                    self.last_plan,
+                    num_frames=self.trash_frame + 1,
+                    trash_frame=self.trash_frame,
+                    staging="gathers-first",
+                )
+            # pad every batch to one shared power-of-two width via the trash
+            # frame (a self-copy of garbage): batch-size jitter then never
+            # forces a gather/scatter recompile
+            self._flush_width = max(self._flush_width, bucket(max(
+                n_demote, len(pending) - n_demote)))
+            batches = []  # (dst_frames, staged_k, staged_v) — gather phase
+            for demote in (True, False):
+                group = [c for c in pending if c.demote == demote]
+                if not group:
+                    continue
+                pad = [self.trash_frame] * (self._flush_width - len(group))
+                src = jnp.asarray([c.src for c in group] + pad, jnp.int32)
+                dst = jnp.asarray([c.dst for c in group] + pad, jnp.int32)
+                batches.append((
+                    dst,
+                    kernel_ops.page_gather(self.k_store, src),
+                    kernel_ops.page_gather(self.v_store, src),
+                ))
+            for dst, staged_k, staged_v in batches:  # scatter phase
+                self.k_store = kernel_ops.page_scatter(self.k_store, dst, staged_k)
+                self.v_store = kernel_ops.page_scatter(self.v_store, dst, staged_v)
 
     def _flush_if_touches(self, gframe: int) -> None:
         if self._pending and (
