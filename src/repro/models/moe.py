@@ -80,8 +80,16 @@ def moe_fwd(
     cfg: MoeConfig,
     x: jax.Array,  # (B, S, d)
     capacity: Optional[int] = None,
+    n_valid: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Returns (y, aux_loss)."""
+    """Returns (y, aux_loss).
+
+    ``n_valid`` (one sequence, ``B == 1``, ``capacity`` unset) marks the
+    first ``n_valid`` tokens as real and the rest as padding: each expert
+    then keeps the tokens it would keep at the real length.  Padding
+    sorts after every real token, so the real tokens' outputs are those
+    of the unpadded call (the auxiliary loss still averages over the
+    padding)."""
     B, S, d = x.shape
     T = B * S
     E, K = cfg.n_experts, cfg.top_k
@@ -115,6 +123,9 @@ def moe_fwd(
     pos = jnp.zeros((T * K,), jnp.int32).at[order].set(pos_sorted).reshape(T, K)
     expert = gate_idx  # (T, K)
     keep = pos < C
+    if n_valid is not None:
+        caps = [max(1, int(cfg.capacity_factor * n * K / E)) for n in range(T + 1)]
+        keep &= pos < jnp.asarray(caps, jnp.int32)[n_valid]
 
     # scatter tokens into (E, C, d)
     slot = jnp.where(keep, expert * C + pos, E * C)  # overflow slot dropped

@@ -28,6 +28,10 @@ Two data planes (``EngineConfig.data_plane``, DESIGN.md §6):
   move in staged ``page_gather``/``page_scatter`` batches.  Identical
   greedy tokens and VmStat trajectories (tests/test_serving_parity.py).
 
+Prefill runs the stack over a prompt as one jitted program
+(``_prefill_program``), compiled once per padded prompt length: the next
+power of two, at least one page.
+
 Phase spans: the decode step, prefill and release are marked with
 ``jax.profiler.TraceAnnotation`` spans named ``serve.*`` (``serve.step``
 around its flush, score, selection, tables, dispatch, policy and interval
@@ -46,6 +50,7 @@ the memory hierarchy.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -59,7 +64,8 @@ from repro.kernels import ops as kernel_ops
 from repro.qos import make_control
 from repro.kernels.paged_attention import PAD_PAGE_POS
 from repro.models import nn
-from repro.models.attention import AttnConfig, make_cos_sin, _rotate
+from repro.models.attention import (AttnConfig, _rotate, make_cos_sin,
+                                    reference_attention)
 from repro.models.ffn import ffn_fwd
 from repro.models.model import ModelConfig
 from repro.models.moe import moe_fwd
@@ -144,6 +150,59 @@ def _flat_layers(params: Any, cfg: ModelConfig) -> List[Any]:
     return out
 
 
+# Padded lengths traced by _prefill_program, one entry per trace (that is,
+# per compile of a new program) in this process.
+_prefill_traces: List[int] = []
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _prefill_program(cfg: ModelConfig, embed: Any, layers: List[Any],
+                     toks: jax.Array, n_real: jax.Array
+                     ) -> Tuple[jax.Array, jax.Array]:
+    """One prompt through the stack → K and V of every layer, each
+    ``(L, S_pad, Hkv, D)``.
+
+    ``toks`` is the prompt padded at the end to ``S_pad``; the first
+    ``n_real`` tokens are real.  Causal attention keeps the padding out of
+    every real token's rows, and MoE layers keep the expert capacity of the
+    real length, so the real rows are those of the unpadded forward.  The
+    layer structure (``cfg``) is static and the weights are arguments, so
+    engines of one configuration share one compile per padded length."""
+    S = toks.shape[0]
+    _prefill_traces.append(S)
+    x = nn.embed(embed, toks[None, :])
+    pos = jnp.arange(S, dtype=jnp.int32)[None, :]
+    k_layers, v_layers = [], []
+    for spec, p in zip(cfg.all_specs(), layers):
+        pa = p["base"] if "base" in p else p
+        a = spec.attn
+        h = nn.rmsnorm(pa["norm1"], x)
+        q = nn.dense(pa["attn"]["wq"], h).reshape(1, S, a.n_heads, a.head_dim)
+        k = nn.dense(pa["attn"]["wk"], h).reshape(1, S, a.n_kv_heads, a.head_dim)
+        v = nn.dense(pa["attn"]["wv"], h).reshape(1, S, a.n_kv_heads, a.head_dim)
+        cos, sin = make_cos_sin(a, pos)
+        if cos is not None:
+            q = _rotate(a, q, cos, sin)
+            k = _rotate(a, k, cos, sin)
+        o = reference_attention(q, k, v, causal=True, window=a.window)
+        y = nn.dense(pa["attn"]["wo"], o.reshape(1, S, -1))
+        if "base" in p:
+            lora = p["lora"]
+            y = y + nn.dense({"w": lora["ob"]}, nn.dense({"w": lora["oa"]},
+                nn.dense({"w": lora["qb"]}, nn.dense({"w": lora["qa"]}, h))))
+        x = x + y
+        if spec.has_ffn:
+            h2 = nn.rmsnorm(pa["norm2"], x)
+            if spec.moe is not None:
+                y2, _ = moe_fwd(pa["moe"], spec.moe, h2, n_valid=n_real)
+            else:
+                y2 = ffn_fwd(pa["ffn"], h2, spec.ffn_kind)
+            x = x + y2
+        k_layers.append(k[0])  # (S, Hkv, D)
+        v_layers.append(v[0])
+    return jnp.stack(k_layers, axis=0), jnp.stack(v_layers, axis=0)
+
+
 class ServingEngine:
     """Batched tiered-KV serving for attention-family architectures."""
 
@@ -204,6 +263,9 @@ class ServingEngine:
         # pid -> (L, Hkv, D) np
         self._summaries: Dict[int, np.ndarray] = {}
         self.steps = 0
+        # prefill programs compiled by this engine's calls (new padded
+        # lengths; engines of one configuration share the programs)
+        self.prefill_compiles = 0
         # per-step per-sequence tier hit split {rid: (fast, slow)} — the
         # traffic front end's latency model reads a lane's own residency
         # from here (refreshed by every step())
@@ -365,58 +427,36 @@ class ServingEngine:
             )
         return seq.pages[-1], slot
 
-    def _prefill_forward(self, req: Request) -> Tuple[jax.Array, jax.Array]:
+    def _prefill_forward(self, req: Request,
+                         padded: int) -> Tuple[jax.Array, jax.Array]:
         """Run the stack over ``prompt[:-1]`` → per-layer K and V.
 
         Returns ``(k_all, v_all)`` of shape ``(L, S, Hkv, D)``.  The last
         prompt token is fed by the first decode step (whose logits
         produce the first generated token) — standard prefill/decode
-        split."""
-        toks = jnp.asarray(req.prompt[:-1], jnp.int32)[None, :]  # (1, S)
-        S = toks.shape[1]
-        x = nn.embed(self.params["embed"], toks)
-        pos = jnp.arange(S, dtype=jnp.int32)[None, :]
-        k_layers, v_layers = [], []
-        for li, spec in enumerate(self.specs):
-            p = self.layers[li]
-            pa = p["base"] if "base" in p else p
-            a = spec.attn
-            h = nn.rmsnorm(pa["norm1"], x)
-            B = 1
-            q = nn.dense(pa["attn"]["wq"], h).reshape(B, S, a.n_heads, a.head_dim)
-            k = nn.dense(pa["attn"]["wk"], h).reshape(B, S, a.n_kv_heads, a.head_dim)
-            v = nn.dense(pa["attn"]["wv"], h).reshape(B, S, a.n_kv_heads, a.head_dim)
-            cos, sin = make_cos_sin(a, pos)
-            if cos is not None:
-                q = _rotate(a, q, cos, sin)
-                k = _rotate(a, k, cos, sin)
-            from repro.models.attention import reference_attention
-
-            o = reference_attention(q, k, v, causal=True, window=a.window)
-            y = nn.dense(pa["attn"]["wo"], o.reshape(B, S, -1))
-            if "base" in p:
-                lora = p["lora"]
-                y = y + nn.dense({"w": lora["ob"]}, nn.dense({"w": lora["oa"]},
-                    nn.dense({"w": lora["qb"]}, nn.dense({"w": lora["qa"]}, h))))
-            x = x + y
-            if spec.has_ffn:
-                h2 = nn.rmsnorm(pa["norm2"], x)
-                if spec.moe is not None:
-                    y2, _ = moe_fwd(pa["moe"], spec.moe, h2)
-                else:
-                    y2 = ffn_fwd(pa["ffn"], h2, spec.ffn_kind)
-                x = x + y2
-            k_layers.append(k[0])  # (S, Hkv, D)
-            v_layers.append(v[0])
-        return jnp.stack(k_layers, axis=0), jnp.stack(v_layers, axis=0)
+        split.  The tokens are padded at the end to ``padded`` and run
+        through :func:`_prefill_program`, compiled once per padded
+        length; ``prefill_compiles`` counts the compiles this engine's
+        calls caused."""
+        S = len(req.prompt) - 1
+        toks = np.zeros(padded, np.int32)
+        toks[:S] = req.prompt[:-1]
+        traced = len(_prefill_traces)
+        k_all, v_all = _prefill_program(
+            self.cfg, self.params["embed"], self.layers, jnp.asarray(toks), S)
+        self.prefill_compiles += len(_prefill_traces) - traced
+        return k_all[:, :S], v_all[:, :S]
 
     def _prefill(self, req: Request) -> None:
         seq = self.seqs[req.rid]
         if len(req.prompt) <= 1:
             return
-        with TraceAnnotation("serve.prefill", rid=req.rid, tokens=len(req.prompt)):
+        # the next power of two, at least one page
+        padded = max(_bucket(len(req.prompt) - 1), self.ecfg.page_size)
+        with TraceAnnotation("serve.prefill", rid=req.rid, tokens=len(req.prompt),
+                             bucket=padded):
             with TraceAnnotation("serve.prefill.forward"):
-                k_all, v_all = self._prefill_forward(req)  # (L, S, Hkv, D)
+                k_all, v_all = self._prefill_forward(req, padded)  # (L, S, Hkv, D)
             with TraceAnnotation("serve.prefill.write"):
                 if self.ecfg.data_plane == "batched":
                     self._prefill_write_batched(seq, k_all, v_all)

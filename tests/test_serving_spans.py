@@ -12,6 +12,7 @@ from repro.configs import get_smoke_config
 from repro.core import TppConfig
 from repro.models.model import init_params
 from repro.serving import EngineConfig, ServingEngine
+from repro.serving.kv_cache import bucket
 
 STEPS = 14
 STEP_CHILDREN = ("serve.flush", "serve.score", "serve.score.wait", "serve.select",
@@ -86,9 +87,11 @@ def test_selection_and_tables_carry_their_counts(served):
 
 
 def test_prefill_carries_rid_and_tokens(served):
-    _, (_, rids, _), spans = served
+    _, (eng, rids, _), spans = served
     pre = _named(spans, "serve.prefill")
     assert [(s[3]["rid"], s[3]["tokens"]) for s in pre] == list(zip(rids, (30, 17, 9)))
+    page = eng.ecfg.page_size
+    assert [s[3]["bucket"] for s in pre] == [max(bucket(n - 1), page) for n in (30, 17, 9)]
     for p in pre:
         assert [s[0] for s in _inside(spans, p) if s[0].startswith("serve.prefill.")] == \
             ["serve.prefill.forward", "serve.prefill.write"]

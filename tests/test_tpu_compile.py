@@ -23,7 +23,7 @@ from repro.kernels import ops
 from repro.kernels.page_migrate import page_gather, page_scatter
 from repro.kernels.paged_attention import paged_attention
 from repro.models.model import init_params
-from repro.serving.engine import ServingEngine, _flat_layers
+from repro.serving.engine import ServingEngine, _flat_layers, _prefill_program
 
 KERNEL = 'custom_call_target="tpu_custom_call"'
 # the served TinyLlama geometry: 16-token pages, 128 fast + 1024 slow
@@ -139,3 +139,30 @@ def test_batched_decode_step_compiles_one_kernel_per_layer(one_chip, ops_on_tpu)
         vec, vec, table, table, vec, vec, vec, vec,
     ).compile()
     assert _kernels(compiled) == cfg.n_layers
+
+
+def test_prefill_program_compiles_at_full_width(one_chip):
+    """The jitted prefill of a 512-token bucket at TinyLlama's full width
+    (two of its layers) takes its weights as arguments, never as constants
+    baked into the program."""
+    full = get_config("tinyllama-1.1b")
+    ((pattern, _),) = full.stacks
+    cfg = dataclasses.replace(full, stacks=((pattern, 2),))
+    a = cfg.all_specs()[0].attn
+    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    layers = jax.eval_shape(lambda p: _flat_layers(p, cfg), params)
+
+    def place(tree):
+        return jax.tree.map(lambda s: _on(one_chip, s.shape, s.dtype), tree)
+
+    compiled = _prefill_program.lower(
+        cfg, place(params["embed"]), place(layers),
+        _on(one_chip, (512,), jnp.int32), _on(one_chip, (), jnp.int32),
+    ).compile()
+    out = compiled.memory_analysis()
+    # the last layer's output projection and FFN feed no K or V, so XLA
+    # drops them; the embedding and the first layer are read in full
+    read = sum(x.size * 4 for x in jax.tree.leaves((params["embed"], layers[0])))
+    assert out.argument_size_in_bytes >= read
+    k_shape = (cfg.n_layers, 512, a.n_kv_heads, a.head_dim)
+    assert [o.shape for o in jax.tree.leaves(compiled.out_info)] == [k_shape, k_shape]
