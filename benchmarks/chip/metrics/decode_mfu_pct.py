@@ -1,7 +1,7 @@
 """Decode step, whole: model FLOPs of the decode steps in the traced part
-(matrix products of each token and attention over the selected pages,
-``tppbench.counts.decode_flops``) over the traced window times the chip's
-peak FLOP/s, in %."""
+(matrix products of each token and attention over the selected pages, as
+the architecture's adapter counts them: ``run.work.decode_flops``) over the
+traced window times the chip's peak FLOP/s, in %."""
 
 
 def read(run):
@@ -9,5 +9,5 @@ def read(run):
         return None
     first, last = run.trace.steps[0], run.trace.steps[len(run.traced_steps) - 1]
     window = (last.end - first.start) / 1e9
-    flops = sum(run.counts.decode_flops(run.shapes, s.cur_lens) for s in run.traced_steps)
+    flops = sum(run.work.decode_flops(s.cur_lens) for s in run.traced_steps)
     return 100.0 * flops / (window * run.peak["flops_per_s"])

@@ -1,7 +1,8 @@
 """Kernels: ``kernels/page_migrate.py``'s scatter kernel, share of its
-roofline over the traced part: every migrated page's K and V payloads read
-once from the staging buffer and written once to their frame
-(``tppbench.counts.page_copy_bytes``), over the kernel's device time, in %."""
+roofline over the traced part: every migrated page's payload in every
+store, read once from the staging buffer and written once to its frame, as
+the architecture's adapter counts it (``run.work.migrate_bytes``), over
+the kernel's device time, in %."""
 
 KERNEL = "page_scatter"
 
@@ -13,5 +14,5 @@ def read(run):
     seconds = sum(e.dur for e in run.trace.calls(run.trace.ops, lambda n: KERNEL in n)) / 1e9
     if not pages or seconds <= 0:
         return None
-    nbytes = 2 * run.counts.page_copy_bytes(run.shapes, pages)
+    nbytes = run.work.migrate_bytes(pages)
     return run.counts.roofline_share(0.0, nbytes, seconds, run.peak)[0]

@@ -1,8 +1,8 @@
 """Kernels: ``kernels/paged_attention.py``'s share of its roofline in the
 traced decode steps: the least time its counted work needs at the chip's
-peaks (K/V of the selected pages of live lanes, q and output;
-``tppbench.counts.paged_attention_call``) over its measured device time,
-in %."""
+peaks (the selected pages of live lanes, q and output, over every layer,
+as the architecture's adapter counts them: ``run.work.attention``) over its
+measured device time, in %."""
 
 KERNEL = "paged_attention"
 
@@ -17,7 +17,7 @@ def read(run):
         return None
     flops = nbytes = 0.0
     for s in run.traced_steps:
-        f, b = run.counts.paged_attention_call(run.shapes, s.cur_lens)
-        flops += f * run.shapes.layers
-        nbytes += b * run.shapes.layers
+        f, b = run.work.attention(s.cur_lens)
+        flops += f
+        nbytes += b
     return run.counts.roofline_share(flops, nbytes, seconds, run.peak)[0]

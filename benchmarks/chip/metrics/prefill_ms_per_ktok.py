@@ -1,5 +1,6 @@
-"""Prefill: host time of ``SlotEngine.prefill`` (``ServingEngine``'s eager
-prefill), ended by a wait for the KV stores, per 1000 prompt tokens of the
+"""Prefill: host time of ``SlotEngine.prefill`` (``ServingEngine``'s
+prefill, jitted per prompt-length bucket, and its write into the KV
+stores), ended by a wait for the stores, per 1000 prompt tokens of the
 counted requests, in ms."""
 
 

@@ -1,8 +1,10 @@
-"""Dense GQA decoder with SwiGLU: the program's model and parameter tree.
+"""Dense GQA decoder with SwiGLU: the program's model and parameter tree,
+and the work the benchmark counts.
 
-Builds the program's ``ModelConfig`` from a configuration file and puts the
+Builds the program's ``ModelConfig`` from a configuration file, puts the
 benchmark's flat weights (``tppbench/weights.py``) into the program's
-parameter tree without copying them.
+parameter tree without copying them, and counts a step's work by the dense
+GQA formulas of ``tppbench/counts.py``.
 """
 
 from __future__ import annotations
@@ -11,7 +13,7 @@ from repro.models.attention import AttnConfig
 from repro.models.model import ModelConfig
 from repro.models.transformer import BlockSpec
 
-from tppbench import weights
+from tppbench import counts, weights
 
 REFERENCE = "dense_gqa"
 
@@ -63,3 +65,25 @@ def program_params(w: dict) -> dict:
         "final_norm": {"scale": w["final_norm"]},
         "lm_head": {"w": w["lm_head"]},
     }
+
+
+class Work:
+    """Counted work of a dense GQA decoder: every layer alike, and pages of
+    K and V in two stores of equal width."""
+
+    def __init__(self, shapes: counts.Shapes) -> None:
+        self.shapes = shapes
+
+    def decode_flops(self, cur_lens) -> float:
+        return counts.decode_flops(self.shapes, cur_lens)
+
+    def attention(self, cur_lens):
+        flops, nbytes = counts.paged_attention_call(self.shapes, cur_lens)
+        return flops * self.shapes.layers, nbytes * self.shapes.layers
+
+    def migrate_bytes(self, pages: int) -> float:
+        return 2 * counts.page_copy_bytes(self.shapes, pages)  # the K and the V store
+
+
+def work(m: dict, engine: dict) -> Work:
+    return Work(counts.shapes(m, engine))

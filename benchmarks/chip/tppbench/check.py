@@ -127,7 +127,7 @@ def reference(cell: spec.Cell, seed: int, reqs: Sequence[Request], fed, chosen=N
     import jax
     import jax.numpy as jnp
 
-    arch = spec.architecture(cell.model["architecture"])
+    arch = spec.architecture(cell.root, cell.model["architecture"])
     ref = spec.reference(cell.root, arch.REFERENCE)
     dims = ref.dims_of(cell.model, cell.engine)
     dtype = jnp.bfloat16 if kind == "bfloat16" else jnp.float32

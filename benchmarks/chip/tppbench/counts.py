@@ -3,9 +3,12 @@
 These count the work itself, whatever implements it: the pages that
 top-k selection picked for live lanes, not padding lanes or pad entries of
 the block table; the page payloads that migrate, each read and written
-once; the matrix products of one token, not a recomputation. The table of
-peaks (``peaks.json``) is keyed by JAX's ``device_kind``; a device that is
-not in it is an error.
+once; the matrix products of one token, not a recomputation. ``attended``
+follows the served page selection, which every architecture shares;
+``Shapes`` and the other formulas are dense GQA's, which its adapter
+(``tppbench/arch/dense_gqa.py``) hands the readers as its ``work``. The
+table of peaks (``peaks.json``) is keyed by JAX's ``device_kind``; a device
+that is not in it is an error.
 """
 
 from __future__ import annotations
@@ -52,7 +55,8 @@ def shapes(model: dict, engine: dict) -> Shapes:
 def attended(s: Shapes, t: int) -> (int, int):
     """(pages, tokens) one lane attends at the step that writes position
     ``t``: the top-k older pages, the recent pages and the write page, and
-    the positions up to ``t`` in them."""
+    the positions up to ``t`` in them. Of ``s`` it reads only the
+    engine's ``page_size``, ``topk_pages`` and ``recent_pages``."""
     P = s.page_size
     n = -(-t // P)  # pages holding positions < t
     older = max(n - s.recent_pages, 0)

@@ -46,8 +46,8 @@ class CompileCounter:
 
 def configure_jax(root: Path) -> None:
     """Persistent compilation cache at a fixed path in the checkout (or
-    where ``JAX_COMPILATION_CACHE_DIR`` says), keeping even the sub-second
-    compiles of the eager prefill."""
+    where ``JAX_COMPILATION_CACHE_DIR`` says), keeping even sub-second
+    compiles, such as the small programs of the prefill's write."""
     import os
 
     import jax
@@ -103,7 +103,7 @@ def build(cell: spec.Cell, seed: int):
     precision = cell.model["matmul_precision"]
     jax.config.update("jax_default_matmul_precision",
                       None if precision == "default" else precision)
-    arch = spec.architecture(cell.model["architecture"])
+    arch = spec.architecture(cell.root, cell.model["architecture"])
     e = cell.engine
     w = weights.make(arch.leaves(cell.model), seed, cell.model["torch_dtype"])
     eng = ServingEngine(
@@ -201,7 +201,8 @@ class RunView:
                  traced_steps=None, device_kind="", traced_counters=None,
                  paused=None) -> None:
         self.cell = cell
-        self.shapes = counts.shapes(cell.model, cell.engine)
+        arch = spec.architecture(cell.root, cell.model["architecture"])
+        self.work = arch.work(cell.model, cell.engine)  # the counts the readers divide
         self.counts = counts
         self.peak = counts.peaks(device_kind) if device_kind else None
         self.requests = requests  # counted requests

@@ -7,10 +7,26 @@
 * per-layer metric: ``benchmarks/chip/metrics/<metric>.py``, whose
   ``read(run)`` returns a number or ``None`` when it finds nothing to read;
 * an architecture's adapter to the program: ``tppbench/arch/<architecture>.py``;
-  its plain reference: ``benchmarks/chip/references/<reference>.py``.
+  its plain reference: ``benchmarks/chip/references/<REFERENCE>.py``.
 
-A new cell, mix, configuration or metric is new files and new entries; no
-file here changes.
+An adapter defines ``REFERENCE``, ``leaves(model)`` (``tppbench/weights.py``),
+``model_config(model)``, ``program_params(weights)``, and ``work(model,
+engine)``: the work the architecture counts, which the per-layer readers
+divide by measured time. ``work`` returns an object with
+
+* ``decode_flops(cur_lens)``: model FLOPs of one decode step over its live
+  lanes, ``cur_lens`` being the tokens each lane had cached before it
+  (``driver.Step.cur_lens``);
+* ``attention(cur_lens) -> (flops, bytes)``: the paged attention kernel's
+  counted work in that step, over every layer;
+* ``migrate_bytes(pages)``: the bytes that one direction's copy programs
+  (``page_gather`` or ``page_scatter``) move over every store for
+  ``pages`` migrated pages.
+
+An adapter that lacks one of these is an error that names its file.
+
+A new cell, mix, configuration, metric or architecture is new files and new
+entries; no file here changes.
 """
 
 from __future__ import annotations
@@ -23,6 +39,7 @@ from types import ModuleType
 from typing import Dict, List
 
 BENCH = Path("benchmarks") / "chip"
+ADAPTER = ("REFERENCE", "leaves", "model_config", "program_params", "work")
 
 
 @dataclasses.dataclass
@@ -74,17 +91,26 @@ def _module(path: Path, name: str) -> ModuleType:
     return mod
 
 
+def _ident(name: str) -> str:
+    return name.replace(".", "_").replace("-", "_")
+
+
 def metric_reader(root: Path, name: str):
     return _module(Path(root) / BENCH / "metrics" / f"{name}.py",
-                   "tppbench_metric_" + name.replace(".", "_").replace("-", "_")).read
+                   "tppbench_metric_" + _ident(name)).read
 
 
 def reference(root: Path, name: str) -> ModuleType:
     return _module(Path(root) / BENCH / "references" / f"{name}.py", "tppbench_ref_" + name)
 
 
-def architecture(name: str) -> ModuleType:
-    return importlib.import_module(f"tppbench.arch.{name}")
+def architecture(root: Path, name: str) -> ModuleType:
+    path = Path(root) / BENCH / "tppbench" / "arch" / f"{name}.py"
+    mod = _module(path, "tppbench_arch_" + _ident(name))
+    missing = [n for n in ADAPTER if not hasattr(mod, n)]
+    if missing:
+        raise AttributeError(f"{path}: the adapter lacks {', '.join(missing)}")
+    return mod
 
 
 def read_metrics(cell: Cell, run) -> Dict[str, dict]:

@@ -37,6 +37,10 @@ SMOKE_CELL = {"rate_rps": 12.0,
 
 CPU = {"platform": "cpu", "kind": "TPU v5 lite", "count": 1}
 
+# two decode steps of the chat cell (phi3-medium-14b-l2, 32 lanes) with the
+# flush programs before each, recorded on a TPU v5e
+TRACE_FIXTURE = Path(__file__).parent / "data" / "trace_chat_v5e.json"
+
 
 def checkout(tmp: Path, *, model=None, mix=None, cell=None, name="smoke.cell",
              config="smoke", traffic="smoke-mix") -> Path:
@@ -70,3 +74,18 @@ def run(root: Path, seed: int, seconds: float, traced: bool = False, name="smoke
     result = harness.run_cell(cell, seed, seconds, traced, time.perf_counter(), dict(CPU),
                               log=lines.append)
     return result, lines
+
+
+def traced_view(root: Path, name: str, lens):
+    """A finished traced window of cell ``name`` over the recorded trace:
+    each step serves lanes with ``lens`` tokens cached, five more a step,
+    and 70 pages migrate while it is traced."""
+    from tppbench import spec, trace
+    from tppbench.driver import Step
+    from tppbench.harness import RunView
+
+    tr = trace.Trace([trace.Event(**e) for e in json.loads(TRACE_FIXTURE.read_text())])
+    steps = [Step(0.0, 0.05, len(lens), [t + 5 * i for t in lens])
+             for i in range(len(tr.steps))]
+    return RunView(spec.load(root, name), [], steps, {"decode_steps": len(steps)}, tr,
+                   steps, "TPU v5 lite", {"migrated_pages": 70})
